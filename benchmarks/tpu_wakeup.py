@@ -25,12 +25,13 @@ _SUB = r"""
 import jax
 from repro.configs import get_config
 from repro.distributed import make_wakeup_step
+from repro.launch.mesh import make_host_mesh
 from repro.launch.roofline import collective_stats
 from repro.models.init import abstract_params, param_bytes
 cfg = get_config("tinyllama-1.1b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model=4)   # 2 data x 4 model, Auto axes
 fn, _, _ = make_wakeup_step(cfg, mesh)
-with mesh:
+with jax.set_mesh(mesh):
     compiled = fn.lower(abstract_params(cfg)).compile()
 cs = collective_stats(compiled.as_text())
 print("BYTES", param_bytes(cfg), cs.total_bytes,
@@ -42,6 +43,9 @@ def run(csv: CSV) -> None:
     print("# TPU-native multipath wake-up (beyond-paper)")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # The child only counts HLO on virtual CPU devices; pinning it to the
+    # CPU keeps it off a TPU that this process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", _SUB], env=env,

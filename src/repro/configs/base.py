@@ -64,8 +64,8 @@ class ModelConfig:
     # instead of the pjit scatter-dispatch formulation.
     moe_ep: bool = False
     # attention implementation: "xla" (einsum, lowers for the dry-run) or
-    # "pallas" (flash kernel in interpret mode — kernels as a first-class
-    # model option, CPU-validated; compiles natively on real TPU).
+    # "pallas" (the flash kernel: compiled on a TPU, interpreted elsewhere;
+    # see kernels/interpret.py).
     attn_impl: str = "xla"
 
     # ------------------------------------------------------------------

@@ -368,6 +368,7 @@ class MMAEngine:
         # kick_all's preemption pass runs first, so the arrival's chunks
         # are not stuck behind outranked pre-wire chunks already pulled.
         self.selector.kick_all()
+        self.backend.settle()
 
     # ------------------------------------------------------------------
     # Tenant observability

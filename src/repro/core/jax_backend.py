@@ -10,16 +10,22 @@ exactly the paper's PCIe-then-NVLink two-hop, expressed in JAX.
 
 Timing claims come from the simulator backend; this backend asserts
 bit-exactness and exercises the Sync Engine with real threads.
+
+Each copy is issued when its chunk is pulled, but the completion is held
+until the dispatch round that pulled it has asked every link
+(``settle``): delivered inline, it would let the first link pull every
+chunk before a relay link had its turn.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from collections import deque
+from typing import Callable, Deque, Dict, List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .config import MMAConfig
 from .engine import MMAEngine
@@ -64,14 +70,21 @@ class ChunkAssembler:
         return len(self.chunks) == self.n_chunks
 
     def result(self, shape, dtype) -> jax.Array:
-        parts = [self.chunks[i] for i in range(self.n_chunks)]
-        out = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+        """The payload, once. The parts are handed over and joined in one
+        concatenation (``jnp.concatenate`` builds a tree of them, a third
+        payload-sized buffer), so device memory peaks at the parts plus
+        the payload."""
+        parts = [self.chunks.pop(i) for i in range(self.n_chunks)]
+        out = lax.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+        del parts
         return out.reshape(shape).astype(dtype)
 
 
 class JaxBackend(Backend):
     def __init__(self, devices: Optional[Sequence] = None) -> None:
         self.devices = list(devices if devices is not None else jax.devices())
+        self._done: Deque[Callable[[], None]] = deque()
+        self._settling = False
 
     def now(self) -> float:
         return time.monotonic()
@@ -79,7 +92,7 @@ class JaxBackend(Backend):
     def launch(
         self, mt: MicroTask, route: Route, on_done: Callable[[], None]
     ) -> None:
-        # Copies run synchronously; there is no recall window, so no
+        # Copies are issued here and cannot be recalled, so no
         # PreemptHandle is returned (preemption is a sim-backend feature).
         task = mt.parent
         payload: HostPayload = (
@@ -109,7 +122,19 @@ class JaxBackend(Backend):
             if not route.is_direct:
                 piece = jax.device_put(piece, relay_dev)       # target -> relay (ICI)
             payload.flat[lo:hi] = np.asarray(piece)            # relay/target -> host
-        on_done()
+        self._done.append(on_done)
+
+    def settle(self) -> None:
+        """Deliver held completions until none is left; each may pull and
+        launch more chunks. Re-entrant calls return at once."""
+        if self._settling:
+            return
+        self._settling = True
+        try:
+            while self._done:
+                self._done.popleft()()
+        finally:
+            self._settling = False
 
 
 def _functional_topology(n_devices: int) -> Topology:

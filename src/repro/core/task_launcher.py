@@ -52,6 +52,11 @@ class Backend:
         None)."""
         raise NotImplementedError
 
+    def settle(self) -> None:
+        """Deliver the completions of a dispatch round, where the backend
+        defers them (the functional backend). The simulator delivers its
+        completions on its own clock."""
+
 
 class SimBackend(Backend):
     """Virtual-time backend: builds per-chunk tandem-queue paths over

@@ -1101,6 +1101,10 @@ class TaskManager:
         self._outstanding[tid] -= 1
         self._bytes_left[tid] -= mt.nbytes
         if len(self._mt_pool) < self.config.sim_micro_pool_size:
+            # A pooled chunk must not keep its task, and with it the
+            # task's payload (device arrays on the functional backend),
+            # alive.
+            mt.parent = None
             self._mt_pool.append(mt)
         if self._outstanding[tid] == 0:
             task = self._tasks.pop(tid)

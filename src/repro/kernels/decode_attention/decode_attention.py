@@ -11,11 +11,14 @@ R = H/G query heads sharing one KV head — the MXU sees an (R x D) x
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..interpret import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -64,7 +67,7 @@ def decode_attention(
     kv_len: jax.Array,     # (B,) int32 valid lengths
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, H, D = q.shape
     G, T = k.shape[1], k.shape[2]
@@ -92,6 +95,6 @@ def decode_attention(
             pltpu.VMEM((R,), jnp.float32),
             pltpu.VMEM((R, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(kv_len, qg, k, v)
     return out.reshape(B, H, D)

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ def decode_attention_op(
     kv_len: jax.Array,
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     use_kernel: bool = True,
 ) -> jax.Array:
     qq = q[:, 0]

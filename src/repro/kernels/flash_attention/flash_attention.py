@@ -10,11 +10,14 @@ tests also sweep smaller toy tiles).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..interpret import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -73,7 +76,7 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, H, Sq, D = q.shape
     G, T = k.shape[1], k.shape[2]
@@ -102,5 +105,5 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),     # running denominator
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)

@@ -3,6 +3,7 @@ model's (B, S, H, D) layout."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ def flash_attention_op(
     q_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     use_kernel: bool = True,
 ) -> jax.Array:
     qt = q.transpose(0, 2, 1, 3)

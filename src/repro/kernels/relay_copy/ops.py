@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ def relay_assemble_op(
     staged: jax.Array,
     perm: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     use_kernel: bool = True,
 ) -> jax.Array:
     if use_kernel:

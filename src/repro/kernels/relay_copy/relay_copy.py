@@ -14,10 +14,14 @@ engine itself performs the scatter/gather — no compute-core shuffling.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..interpret import pallas_interpret
 
 
 def _copy_kernel(perm_ref, staged_ref, out_ref):
@@ -28,22 +32,28 @@ def relay_assemble(
     staged: jax.Array,    # (n_chunks, chunk_elems) rows in landing order
     perm: jax.Array,      # (n_chunks,) perm[i] = staged row of logical chunk i
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     n_chunks, chunk_elems = staged.shape
+    # Each block is one whole chunk, viewed as (rows, lanes) so that the
+    # block's last two dimensions equal the array's, as the TPU's tiling
+    # requires: lane-dense rows of 128 when the chunk divides into them,
+    # else a single row.
+    lanes = 128 if chunk_elems % 128 == 0 else chunk_elems
+    rows = chunk_elems // lanes
+    block = (1, rows, lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_chunks,),
         in_specs=[
-            pl.BlockSpec(
-                (1, chunk_elems), lambda i, perm_ref: (perm_ref[i], 0)
-            ),
+            pl.BlockSpec(block, lambda i, perm_ref: (perm_ref[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk_elems), lambda i, perm_ref: (i, 0)),
+        out_specs=pl.BlockSpec(block, lambda i, perm_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _copy_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(staged.shape, staged.dtype),
-        interpret=interpret,
-    )(jnp.asarray(perm, jnp.int32), staged)
+        out_shape=jax.ShapeDtypeStruct((n_chunks, rows, lanes), staged.dtype),
+        interpret=pallas_interpret(interpret),
+    )(jnp.asarray(perm, jnp.int32), staged.reshape(n_chunks, rows, lanes))
+    return out.reshape(n_chunks, chunk_elems)

@@ -3,6 +3,7 @@ scan: a drop-in alternative to ``models.ssm.ssd_chunked`` for g=1."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ def ssd_op(
     C: jax.Array,        # (b, l, 1, n)
     *,
     chunk: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     use_kernel: bool = True,
 ):
     """Returns (y (b,l,h,p), final_state (b,h,p,n))."""

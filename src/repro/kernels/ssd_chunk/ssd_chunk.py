@@ -12,11 +12,14 @@ inputs. The sequential inter-chunk recurrence is composed outside
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..interpret import pallas_interpret
 
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, d_ref):
@@ -47,7 +50,7 @@ def ssd_chunk(
     B: jax.Array,        # (BH, nc, Q, N)
     C: jax.Array,        # (BH, nc, Q, N)
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (y_diag (BH,nc,Q,P), states (BH,nc,P,N), out_decay (BH,nc,Q))."""
     BH, nc, Q, P = xbar.shape
@@ -71,5 +74,5 @@ def ssd_chunk(
             jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
             jax.ShapeDtypeStruct((BH, nc, Q), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(xbar, a, B, C)

@@ -177,7 +177,7 @@ def dryrun_one(
     specs = input_specs(cfg, shape)
     t0 = time.monotonic()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = abstract_params(cfg)
         p_sh = params_shardings(params, mesh)
         rep = replicated(mesh)
@@ -248,7 +248,7 @@ def dryrun_one(
     coll = collective_stats(hlo)
 
     if scan_layers and correct_scan and cfg.n_periods > 1:
-        with mesh:
+        with jax.set_mesh(mesh):
             body = _body_cost(cfg, shape, mesh, kind, specs, params)
         k = cfg.n_periods - 1
         flops += k * body["flops"]

@@ -3,10 +3,19 @@
 A FUNCTION, not a module-level constant: importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS before first jax init;
 smoke tests must keep seeing 1 device).
+
+Every axis is ``AxisType.Auto``: the model states its layout through
+``with_sharding_constraint`` (``models.layers.shard``), which only binds
+Auto axes. Enter a mesh with ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,11 +23,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the locally-available devices (tests/examples)."""
     n = len(jax.devices())
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -1,8 +1,11 @@
-"""Serving launcher: functional server (reduced arch) with MMA-backed KV
-offload / prefix cache, plus the paper-scale latency model.
+"""Serving launcher: the functional server, with prefix-cache accounting,
+for any registered model (the assigned ARCHS and the paper's
+PAPER_MODELS) at its published widths, or reduced for a CPU run.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-      --requests 6 [--max-new 8]
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
+      --requests 6 [--max-new 8]                       # on a TPU
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve \
+      --arch tinyllama-1.1b --reduced                  # on the CPU
 """
 from __future__ import annotations
 
@@ -10,23 +13,33 @@ import argparse
 
 import numpy as np
 
-from ..configs import ARCHS, get_config
-from ..serving import FunctionalServer, LatencyModel
+from ..configs import ARCHS, PAPER_MODELS, get_config
+from ..serving import FunctionalServer
+from .compile_cache import CompileCounter, enable_compile_cache
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="tinyllama-1.1b", choices=sorted(ARCHS))
+    ap.add_argument("--arch", default="tinyllama-1.1b",
+                    choices=sorted({**ARCHS, **PAPER_MODELS}))
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the 2-layer variant of the family (CPU runs)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=6)
     ap.add_argument("--prompt-len", type=int, default=48)
+    ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--repeat-every", type=int, default=3,
                     help="every Nth request reuses a prompt (prefix hits)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
-    srv = FunctionalServer(cfg, max_running=2, device_budget_tokens=4096,
-                           max_len=256, page_size=16)
+    cache_dir = enable_compile_cache()
+    compiles = CompileCounter()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    srv = FunctionalServer(cfg, max_running=2,
+                           device_budget_tokens=2 * args.max_len,
+                           max_len=args.max_len, page_size=16)
     rng = np.random.default_rng(0)
     base_prompt = rng.integers(0, cfg.vocab, size=args.prompt_len)
     for i in range(args.requests):
@@ -42,14 +55,7 @@ def main() -> None:
     hits = sum(1 for r in done if r.hit_tokens)
     print(f"{len(done)} served, {hits} prefix hits; transfers: "
           f"{srv.transfer_log}")
-
-    full = ARCHS[args.arch]
-    lm_b = LatencyModel(full, use_mma=False)
-    lm_m = LatencyModel(full, use_mma=True)
-    tb, tm = lm_b.ttft(32_768), lm_m.ttft(32_768)
-    print(f"\npaper-scale ({full.name}, 32k prefix hit on 8xH20): "
-          f"TTFT {tb.ttft_s * 1e3:.0f} -> {tm.ttft_s * 1e3:.0f} ms "
-          f"({tb.ttft_s / tm.ttft_s:.2f}x)")
+    print(compiles.summary(cache_dir))
 
 
 if __name__ == "__main__":

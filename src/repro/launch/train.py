@@ -2,7 +2,7 @@
 locally available devices with the production sharding rules.
 
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
-      --steps 100 [--reduced] [--batch 8] [--seq 128] [--model-parallel 1]
+      --steps 100 [--no-reduced] [--batch 8] [--seq 128] [--model-parallel 1]
 
 On a real TPU slice the same entry point picks up all devices; on CPU it
 demonstrates the full path (mesh, sharded params, jitted step, data
@@ -27,6 +27,7 @@ from ..training import (
     init_adamw,
     make_train_step,
 )
+from .compile_cache import CompileCounter, enable_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -36,12 +37,17 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the 2-layer variant (default); --no-reduced "
+                         "trains the published widths")
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--moe-ep", action="store_true")
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    compiles = CompileCounter()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -62,7 +68,7 @@ def main() -> None:
                         total_steps=args.steps),
     )
     step = make_train_step(cfg, tc)
-    with mesh:
+    with jax.set_mesh(mesh):
         p_sh = params_shardings(params, mesh)
         o_sh = type(opt)(
             step=None,
@@ -77,6 +83,7 @@ def main() -> None:
             if i % max(args.steps // 10, 1) == 0 or i == args.steps - 1:
                 print(f"step {i:4d}  loss {float(metrics['loss']):.4f}  "
                       f"gnorm {float(metrics['grad_norm']):.2f}")
+    print(compiles.summary(cache_dir))
     print("done")
 
 

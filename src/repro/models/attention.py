@@ -113,8 +113,7 @@ def self_attention(
         while S % bq:
             bq //= 2
         o = flash_attention_op(
-            q, k, v, causal=True, window=window,
-            block_q=bq, block_k=bq, interpret=True,
+            q, k, v, causal=True, window=window, block_q=bq, block_k=bq,
         )
     else:
         mask = causal_mask(pos, pos, window)

@@ -20,28 +20,10 @@ MODEL = "model"
 
 
 def _current_mesh():
-    """The mesh governing this trace: the sharding-in-types abstract mesh
-    if set, else the legacy ``with mesh:`` context (which is how pjit
-    launchers and the dry-run provide it)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            return am
-    except Exception:  # pragma: no cover
-        pass
-    try:
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            from jax.interpreters import pxla
-
-            pm = pxla.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:  # pragma: no cover
-        pass
-    return None
+    """The mesh governing this trace, as set by ``jax.set_mesh`` (how the
+    launchers and the dry-run provide it), or None."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def active_mesh_axes() -> frozenset:

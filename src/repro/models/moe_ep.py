@@ -12,7 +12,6 @@ full-buffer all-reduces — a ~40x reduction.
 """
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Dict, Tuple
 
@@ -22,19 +21,6 @@ from jax.sharding import PartitionSpec as P
 
 from .layers import _current_mesh, mesh_axis_sizes
 from .moe import moe_ffn
-
-# jax >= 0.6 exposes shard_map at top level; 0.4.x has it under
-# jax.experimental. The replication-check knob was renamed check_rep ->
-# check_vma independently of that move, so pick it from the signature.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 
 def _batch_axes(sizes) -> Tuple[str, ...]:
@@ -112,13 +98,13 @@ def moe_ffn_ep(params: Dict, x: jax.Array, cfg, *, return_aux: bool = False):
         aux = E * jnp.sum(fr * mp)
         return y, aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), x_spec),
         out_specs=(x_spec, P()),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)
     if S_pad != S:
         y = y[:, :S]

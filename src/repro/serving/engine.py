@@ -6,10 +6,11 @@ Two cooperating layers:
   MMA link simulator: produces the paper-comparable TTFT / switching
   numbers (Figs 12-13) for full-size models that cannot run on this CPU.
 
-* ``FunctionalServer`` — actually serves a (reduced) model on CPU with
-  continuous request scheduling, real prefill/decode, real KV offload /
-  prefix-cache fetch round-trips through the functional MMA data plane.
-  Used by integration tests and examples.
+* ``FunctionalServer`` — actually serves a model (reduced on CPU, at its
+  published widths on a TPU) with continuous request scheduling, real
+  jitted prefill/decode and prefix-cache accounting. Its KV transfers are
+  still timed on a simulator. Used by integration tests, examples and
+  ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -39,6 +40,14 @@ H20_BF16_TFLOPS = 148e12
 H20_HBM_GBPS = 4_000e9        # HBM3 ~4 TB/s on H20
 COMPUTE_EFF = 0.45            # achieved fraction during prefill
 DECODE_EFF = 0.6              # achieved fraction of HBM bw during decode
+
+# The served model, compiled once per (cfg, max_len, window) and input
+# shape: called eagerly, the layer scan is compiled again on every call.
+# A decode step donates the caches it replaces.
+jit_prefill = jax.jit(prefill, static_argnames=("cfg", "max_len", "window"))
+jit_decode_step = jax.jit(
+    decode_step, static_argnames=("cfg", "window"), donate_argnames=("caches",)
+)
 
 
 @dataclasses.dataclass
@@ -187,9 +196,9 @@ class LatencyModel:
 # Functional server (reduced models, real arrays)
 # ---------------------------------------------------------------------------
 class FunctionalServer:
-    """Continuous serving of a reduced model on CPU: FCFS scheduling,
-    prefill, per-request decode, KV offload on preemption, prefix-cache
-    reuse with real payload round-trips.
+    """Continuous serving of a real model: FCFS scheduling, prefill,
+    per-request decode, prefix-cache accounting on offload and fetch. A
+    finished request's device KV is dropped.
 
     Admission-control caveat: this loop drains its sim engine
     synchronously after every transfer (``sim_world.run()``), so the
@@ -213,11 +222,14 @@ class FunctionalServer:
         now_fn: Optional[Any] = None,
     ) -> None:
         self.cfg = cfg
-        self.params = (
-            params
-            if params is not None
-            else init_params(jax.random.PRNGKey(seed), cfg)
-        )
+        if params is None:
+            params = jax.jit(init_params, static_argnums=1)(
+                jax.random.PRNGKey(seed), cfg
+            )
+        # Committed to the device (no copy), as weights woken by a
+        # WeightManager are: the jitted model keys its executables on
+        # commitment, and would otherwise compile again after a wake.
+        self.params = jax.device_put(params, jax.devices()[0])
         # Sim engine for transfer accounting (timing) — the payloads
         # themselves are stored/restored as numpy in the host pool.
         self.sim_engine, self.sim_world, _ = make_sim_engine()
@@ -278,7 +290,7 @@ class FunctionalServer:
             # KV, verified by tests); a payload round-trip would skip it.
             self.transfer_log.append(("fetch", hit))
             req.hit_tokens = hit
-        logits, caches, clen = prefill(
+        logits, caches, clen = jit_prefill(
             self.params, toks, self.cfg, max_len=self.max_len
         )
         req.context = {"caches": caches, "cache_len": clen}
@@ -289,22 +301,26 @@ class FunctionalServer:
     def _decode_one(self, req: Request) -> None:
         ctx = req.context
         tok = jnp.asarray([req.generated[-1]], jnp.int32)
-        logits, caches = decode_step(
+        logits, caches = jit_decode_step(
             self.params, tok, ctx["caches"], ctx["cache_len"], self.cfg
         )
         ctx["caches"] = caches
         ctx["cache_len"] = ctx["cache_len"] + 1
         req.generated.append(int(jnp.argmax(logits[0])))
 
+    def release_params(self) -> Any:
+        """Hand the weights over and drop the server's own reference, so
+        that a ``WeightManager.sleep`` of them frees their HBM. Serving
+        resumes once ``self.params`` is set again (e.g. to the woken
+        ``WeightManager.params``)."""
+        params, self.params = self.params, None
+        return params
+
     def step(self) -> None:
         """One engine iteration: admit, prefill new, decode running."""
+        if self.params is None:
+            raise RuntimeError("weights released; set params before serving")
         admitted = self.scheduler.schedule()
-        if not admitted and not self.scheduler.running and (
-            self.scheduler.waiting or self.scheduler.preempted
-        ):
-            # stuck: budget exhausted with nothing running -> preempt path
-            # has already run; force-admit smallest waiting request
-            pass
         for req in admitted:
             self._prefill(req)
         for req in list(self.scheduler.running):
@@ -322,6 +338,7 @@ class FunctionalServer:
                 )
                 self.sim_world.run()
                 self.transfer_log.append(("offload", len(full)))
+                req.context = None          # frees the request's device KV
                 self.scheduler.finish(req)
             else:
                 self._decode_one(req)

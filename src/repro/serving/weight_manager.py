@@ -4,7 +4,11 @@
 ``sleep()`` moves all parameter bytes D2H; ``wake()`` moves them back H2D.
 On the sim backend the returned latencies are the paper-comparable
 numbers; on the functional backend the parameter arrays actually round-trip
-through host memory (bit-exact, used by tests and examples).
+through host memory (bit-exact), timed on the host clock until the last
+byte has landed.
+
+A sleep frees the device memory only if nothing else holds the params: a
+``FunctionalServer`` hands them over with ``release_params()`` first.
 """
 from __future__ import annotations
 
@@ -59,7 +63,7 @@ class WeightManager:
         self.nbytes = (
             nbytes
             if nbytes is not None
-            else sum(np.asarray(l).nbytes for l in jax.tree.leaves(params))
+            else sum(l.nbytes for l in jax.tree.leaves(params))
         )
         self.target = target_device
         self.state = "awake"
@@ -123,6 +127,7 @@ class WeightManager:
                 ),
                 self._host_copy,
             )
+            jax.block_until_ready(self.params)
             self._host_copy = None
             dt = time.monotonic() - t0
             report = TransferReport(self.nbytes, dt,

@@ -57,6 +57,33 @@ def test_param_sharding_rules_divisibility():
     assert emb2 == P(None, None)            # 50280 % 16 != 0 -> replicated
 
 
+def test_shard_binds_host_mesh_axes():
+    """Under ``jax.set_mesh(make_host_mesh())`` ``shard()`` sees the mesh
+    and constrains with its axes; a quiet fall-through to "no mesh" would
+    leave the constraint out of the lowered program."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.layers import BATCH, MODEL, mesh_axis_sizes, pspec, shard
+
+    mesh = make_host_mesh()
+    n = len(jax.devices())
+    assert pspec(BATCH, MODEL) == P(None, None)     # no mesh: no axes
+    with jax.set_mesh(mesh):
+        assert mesh_axis_sizes() == {"data": n, "model": 1}
+        assert pspec(BATCH, MODEL) == P("data", "model")
+        f = jax.jit(lambda x: shard(x * 2, BATCH, MODEL))
+        x = jnp.ones((4 * n, 8))
+        text = f.lower(x).as_text()
+        out = f(x)
+    constraints = [l for l in text.splitlines() if "sharding_constraint" in l]
+    assert len(constraints) == 1, text
+    assert '[{"data"}, {"model"}]' in constraints[0]
+    assert out.sharding.mesh.axis_names == ("data", "model")
+    assert mesh_axis_sizes() == {}          # no mesh outside the context
+
+
 def test_train_step_on_8dev_mesh_subprocess():
     """A reduced model train step lowers, compiles and RUNS sharded on a
     (2 data x 4 model) mesh; loss finite."""
@@ -72,13 +99,14 @@ cfg = dataclasses.replace(
     get_config("olmoe-1b-7b").reduced(), dtype=jnp.float32,
     n_experts=4, top_k=2, moe_ep=True,
 )
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model=4)
 params = init_params(jax.random.PRNGKey(0), cfg)
 opt = init_adamw(params)
 step = make_train_step(cfg, TrainConfig(remat=True, opt=AdamWConfig()))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab)
 batch = {"tokens": toks, "labels": toks}
-with mesh:
+with jax.set_mesh(mesh):
     p_sh = params_shardings(params, mesh)
     b_sh = batch_shardings(batch, mesh)
     o_sh = type(opt)(step=None, mu=params_shardings(opt.mu, mesh),
@@ -115,8 +143,9 @@ params = {
   "w_down": jax.random.normal(ks[3], (E, f, d)) * f**-0.5,
 }
 x = jax.random.normal(ks[4], (2, 16, d)) * 0.5
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-with mesh:
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model=4)
+with jax.set_mesh(mesh):
     ep = jax.jit(lambda p, xx: moe_ffn_ep(p, xx, cfg))(params, x)
 ref = moe_ffn(params, x, cfg)
 err = float(jnp.abs(ep - ref).max())
@@ -135,10 +164,11 @@ import jax
 from repro.configs import get_config
 from repro.distributed import make_wakeup_step
 cfg = get_config("tinyllama-1.1b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model=4)
 fn, stage_sh, serve_sh = make_wakeup_step(cfg, mesh)
 from repro.models.init import abstract_params
-with mesh:
+with jax.set_mesh(mesh):
     compiled = fn.lower(abstract_params(cfg)).compile()
 hlo = compiled.as_text()
 n_coll = sum(hlo.count(k) for k in ("all-gather", "collective-permute",

@@ -67,6 +67,30 @@ def test_odd_sizes_and_chunk_alignment():
         assert np.array_equal(np.asarray(y), x)
 
 
+def test_finished_transfers_hold_no_device_memory():
+    """Once a transfer has landed and its result is dropped, the engine
+    holds none of its device arrays: a weight sleep must free the HBM.
+    Sizes are odd so that no other live array matches them."""
+    import gc
+
+    eng = make_functional_engine(
+        config=MMAConfig(chunk_bytes=4 * 1009, fallback_bytes=0)
+    )
+
+    def live(n_elems):
+        gc.collect()
+        return [a for a in jax.live_arrays() if a.size == n_elems]
+
+    x = jax.numpy.arange(123 * 101, dtype=jax.numpy.float32).reshape(123, 101)
+    host = multipath_device_get(x, engine=eng)        # D2H of a flat view
+    del x
+    assert not live(123 * 101)
+    y = multipath_device_put(host, engine=eng)        # H2D in 1009-elem chunks
+    assert np.array_equal(np.asarray(y), host)
+    del y
+    assert not live(123 * 101) and not live(1009)
+
+
 def test_relay_forwarding_multi_device_subprocess():
     """Run the relay data-plane on 8 virtual devices in a subprocess (the
     device count must not leak into this process — see dryrun.py note)."""
@@ -98,6 +122,47 @@ def test_relay_forwarding_multi_device_subprocess():
     )
     assert out.returncode == 0, out.stderr
     assert "RELAY_OK" in out.stdout
+
+
+def test_every_relay_link_carries_chunks_default_config_subprocess():
+    """Under the default config (direct priority on), every relay link of
+    a 4-device host carries chunks in both directions, and the result
+    lands on the target. Completions are held until the dispatch round
+    has asked every link; delivered inline, the direct link would drain
+    the whole payload before a relay had its turn."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import numpy as np, jax\n"
+        "from repro.core import (MMAConfig, make_functional_engine,\n"
+        "    multipath_device_get, multipath_device_put)\n"
+        "assert len(jax.devices()) == 4\n"
+        "cfg = lambda: MMAConfig(chunk_bytes=1 << 16, fallback_bytes=0)\n"
+        "x = np.arange(1 << 20, dtype=np.float32)\n"
+        "h2d = make_functional_engine(config=cfg())\n"
+        "y = multipath_device_put(x, target=0, engine=h2d)\n"
+        "assert y.devices() == {jax.devices()[0]}\n"
+        "d2h = make_functional_engine(config=cfg())\n"
+        "z = multipath_device_get(y, target=0, engine=d2h)\n"
+        "assert np.array_equal(np.asarray(y), x) and np.array_equal(z, x)\n"
+        "for eng in (h2d, d2h):\n"
+        "    relay = [eng.workers[d].chunks_relay for d in (1, 2, 3)]\n"
+        "    assert all(r > 0 for r in relay), relay\n"
+        "    assert eng.workers[0].chunks_direct > 0\n"
+        "print('ALL_LINKS_OK')\n"
+    )
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr
+    assert "ALL_LINKS_OK" in out.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -119,6 +119,18 @@ def test_weight_manager_functional_roundtrip_exact():
     eng = make_functional_engine(
         config=MMAConfig(chunk_bytes=1 << 17, fallback_bytes=0)
     )
+
+    class DeviceOnlyLeaf:
+        """Knows its size; copying it to the host fails the test."""
+
+        def __init__(self, leaf):
+            self.nbytes = leaf.nbytes
+
+        def __array__(self, *args, **kwargs):
+            raise AssertionError("WeightManager copied a leaf D2H to size it")
+
+    sized = WeightManager(eng, params=jax.tree.map(DeviceOnlyLeaf, params))
+    assert sized.nbytes == sum(l.nbytes for l in jax.tree.leaves(before))
     wm = WeightManager(eng, params=params)
     wm.sleep()
     assert wm.params is None and wm.state == "asleep"
@@ -127,6 +139,41 @@ def test_weight_manager_functional_roundtrip_exact():
     wm.wake()
     for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(wm.params)):
         assert np.array_equal(a, np.asarray(b))
+
+
+def test_server_serves_woken_weights_without_compiling():
+    """A sleep/wake of the served weights through the functional engine
+    keeps the jitted model's executables: the woken weights match what the
+    server held (same device, committed), generate the same tokens, and a
+    sleep drops the server's reference first."""
+    from repro.launch.compile_cache import CompileCounter
+
+    cfg = get_config("tinyllama-1.1b").reduced()
+    srv = FunctionalServer(cfg, max_running=1, device_budget_tokens=2048,
+                           max_len=128, page_size=16)
+    prompt = np.random.default_rng(0).integers(0, cfg.vocab, size=32)
+    first = srv.submit(prompt, max_new_tokens=3)
+    srv.run_until_done()
+    wm = WeightManager(
+        make_functional_engine(
+            config=MMAConfig(chunk_bytes=1 << 16, fallback_bytes=0)
+        ),
+        params=srv.release_params(),
+    )
+    assert srv.params is None
+    with pytest.raises(RuntimeError, match="weights released"):
+        srv.step()
+    wm.sleep()
+    wm.wake()
+    srv.params = wm.params
+    counter = CompileCounter()
+    try:
+        again = srv.submit(prompt, max_new_tokens=3)
+        srv.run_until_done()
+    finally:
+        counter.close()
+    assert again.generated == first.generated
+    assert counter.built == 0
 
 
 def test_model_switch_pair():
