@@ -14,9 +14,10 @@ two engine instances can share one backend to model concurrent MMA flows
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Callable, Dict, List, Optional, Sequence
 
-from ..obs import MetricsRegistry
+from ..obs import MetricsRegistry, span
 from .config import MMAConfig
 from .path_selector import LinkWorker, PathSelector, Route
 from .sync_engine import DummyTask, SyncEngine
@@ -286,11 +287,16 @@ class MMAEngine:
         Policy rides in ``spec=TransferSpec(...)`` — same contract as
         ``memcpy_async``."""
         spec = resolve_transfer_spec("MMAEngine.memcpy", spec, legacy)
-        task = self._make_task(
-            nbytes, device, direction, sync=True, src=src, dst=dst,
-            spec=spec,
-        )
-        self._activate(task)
+        # Only the functional data plane's transfers are spanned: a
+        # simulated one (the served path's KV store) moves no data.
+        timed = (nullcontext() if isinstance(self.backend, SimBackend)
+                 else span("engine.memcpy"))
+        with timed:
+            task = self._make_task(
+                nbytes, device, direction, sync=True, src=src, dst=dst,
+                spec=spec,
+            )
+            self._activate(task)
         return task
 
     # ------------------------------------------------------------------

@@ -27,6 +27,7 @@ import jax
 import numpy as np
 from jax import lax
 
+from ..obs import span
 from .config import MMAConfig
 from .engine import MMAEngine
 from .path_selector import Route
@@ -108,20 +109,25 @@ class JaxBackend(Backend):
         relay_dev = self.devices[route.link_dev]
 
         if mt.direction == Direction.H2D:
-            view = payload.flat[lo:hi]
-            if route.is_direct:
-                chunk = jax.device_put(view, target_dev)       # host -> target
-            else:
-                staged = jax.device_put(view, relay_dev)       # host -> relay (PCIe)
-                chunk = jax.device_put(staged, target_dev)     # relay -> target (ICI)
+            with span("dataplane.h2d_chunk"):
+                view = payload.flat[lo:hi]
+                if route.is_direct:
+                    chunk = jax.device_put(view, target_dev)    # host -> target
+                else:
+                    staged = jax.device_put(view, relay_dev)    # host -> relay (PCIe)
+                    chunk = jax.device_put(staged, target_dev)  # relay -> target (ICI)
             assembler: ChunkAssembler = task.dst
             assembler.add(mt.seq, chunk)
         else:
-            src_flat: jax.Array = task.src                     # on target device
-            piece = src_flat[lo:hi]
-            if not route.is_direct:
-                piece = jax.device_put(piece, relay_dev)       # target -> relay (ICI)
-            payload.flat[lo:hi] = np.asarray(piece)            # relay/target -> host
+            with span("dataplane.d2h_chunk"):
+                src_flat: jax.Array = task.src                  # on target device
+                piece = src_flat[lo:hi]
+                if not route.is_direct:
+                    piece = jax.device_put(piece, relay_dev)    # target -> relay (ICI)
+                with span("dataplane.d2h_wait"):
+                    host = np.asarray(piece)                    # relay/target -> host
+                with span("dataplane.d2h_store"):
+                    payload.flat[lo:hi] = host
         self._done.append(on_done)
 
     def settle(self) -> None:

@@ -8,7 +8,10 @@ and TTFT critical-path attribution.
   * ``repro.obs.export``      — Chrome-trace/Perfetto JSON export +
     schema validation (``python -m repro.obs.export``);
   * ``repro.obs.attribution`` — per-request TTFT decomposition that
-    provably sums to measured TTFT, from the span trees.
+    provably sums to measured TTFT, from the span trees;
+  * ``repro.obs.spans``       — ``span(name)``: host spans of the
+    transfer engine and the data plane on the JAX profiler's clock,
+    counted in ``SPAN_METRICS`` only while a profiler session runs.
 
 This package imports nothing from ``repro.core`` (the core imports
 *us*), so instrumentation can thread through every layer without
@@ -29,6 +32,7 @@ from .metrics import (
     LogHistogram,
     MetricsRegistry,
 )
+from .spans import SPAN_METRICS, span
 from .tracer import (
     NULL_TRACER,
     NullTracer,
@@ -46,6 +50,7 @@ __all__ = [
     "to_chrome", "validate_chrome_trace", "write_chrome_trace",
     "BinnedTimeline", "Counter", "Gauge", "LogHistogram",
     "MetricsRegistry",
+    "SPAN_METRICS", "span",
     "NULL_TRACER", "NullTracer", "Span", "Tracer", "current_tracer",
     "install", "spans_from_dicts", "uninstall",
 ]
