@@ -15,19 +15,24 @@ Each copy is issued when its chunk is pulled, but the completion is held
 until the dispatch round that pulled it has asked every link
 (``settle``): delivered inline, it would let the first link pull every
 chunk before a relay link had its turn.
+
+A D2H payload comes from the backend's ``HostBlockCache``: the next sleep
+of the same sizes stores into pages the last one already faulted in.
 """
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
+import weakref
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Sequence
+from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax import lax
 
-from ..obs import span
+from ..obs import count, span
 from .config import MMAConfig
 from .engine import MMAEngine
 from .path_selector import Route
@@ -54,6 +59,79 @@ class HostPayload:
     @property
     def itemsize(self) -> int:
         return self.flat.dtype.itemsize
+
+
+class _Lease:
+    """The root of every array made from one lent block: numpy collapses
+    view bases onto it and a zero-copy ``device_put`` holds it, so it is
+    collected only once nothing references the block's memory. (A
+    ``memoryview`` cannot be the root: ``np.frombuffer`` wraps it in a new
+    one and drops it at once.)"""
+
+    __slots__ = ("__array_interface__", "block", "__weakref__")
+
+    def __init__(self, block: np.ndarray) -> None:
+        self.block = block
+        self.__array_interface__ = {
+            "data": (block.ctypes.data, False), "shape": (block.nbytes,),
+            "typestr": "|u1", "version": 3,
+        }
+
+
+def _give_back(cache_ref: "weakref.ref[HostBlockCache]",
+               block: np.ndarray) -> None:
+    cache = cache_ref()
+    if cache is not None:
+        cache.give_back(block)
+
+
+class HostBlockCache:
+    """Caching host allocator for D2H payloads.
+
+    A fresh ``np.empty`` of a leaf's size is mmap'd, and the store into it
+    faults its pages in 4 KiB at a time; reused pages take the store at copy
+    speed. Blocks are binned by exact byte size: ``take`` serves a payload
+    from its bin (a hit) or allocates (a miss). A block goes back to its bin
+    when its ``_Lease`` is collected, i.e. when no array, view or zero-copy
+    device buffer made from it is left, so there is no ``release()`` to
+    misuse. Free bytes kept never exceed the most bytes ever lent at once; a
+    block that would pass that mark is dropped. Blocks lent out when the
+    cache is collected are freed with their last reference.
+    """
+
+    def __init__(self) -> None:
+        self._free: Dict[int, List[np.ndarray]] = {}
+        # Blocks come back on any thread, and on this one from a garbage
+        # collection that an allocation under the lock sets off.
+        self._lock = threading.RLock()
+        self.free_bytes = 0
+        self.lent_bytes = 0
+        self.peak_lent_bytes = 0
+
+    def take(self, nbytes: int, dtype: np.dtype) -> Tuple[np.ndarray, bool]:
+        """A 1-D ``dtype`` array over ``nbytes`` of host memory, and whether
+        it came from the cache."""
+        with self._lock:
+            bin_ = self._free.get(nbytes)
+            block = bin_.pop() if bin_ else None
+            if block is not None:
+                self.free_bytes -= nbytes
+            self.lent_bytes += nbytes
+            self.peak_lent_bytes = max(self.peak_lent_bytes, self.lent_bytes)
+        reused = block is not None
+        if block is None:
+            block = np.empty(nbytes, np.uint8)
+        lease = _Lease(block)
+        weakref.finalize(lease, _give_back, weakref.ref(self), block).atexit = False
+        return np.asarray(lease).view(dtype), reused
+
+    def give_back(self, block: np.ndarray) -> None:
+        n = block.nbytes
+        with self._lock:
+            self.lent_bytes -= n
+            if self.free_bytes + n <= self.peak_lent_bytes:
+                self._free.setdefault(n, []).append(block)
+                self.free_bytes += n
 
 
 class ChunkAssembler:
@@ -84,6 +162,7 @@ class ChunkAssembler:
 class JaxBackend(Backend):
     def __init__(self, devices: Optional[Sequence] = None) -> None:
         self.devices = list(devices if devices is not None else jax.devices())
+        self.host_blocks = HostBlockCache()
         self._done: Deque[Callable[[], None]] = deque()
         self._settling = False
 
@@ -207,11 +286,18 @@ def multipath_device_get(
 ) -> np.ndarray:
     """D2H: fetch a device array back to host memory over all paths.
 
-    Same ``spec=``/legacy-kwarg contract as ``multipath_device_put``."""
+    The array comes from ``engine``'s host block cache (``HostBlockCache``);
+    its block is reused once the array and all views of it are gone. Same
+    ``spec=``/legacy-kwarg contract as ``multipath_device_put``."""
     spec = resolve_transfer_spec("multipath_device_get", spec, legacy)
     eng = engine or make_functional_engine()
+    backend: JaxBackend = eng.backend  # type: ignore[assignment]
     shape, dtype = jarr.shape, np.dtype(jarr.dtype)
-    out = np.empty(int(np.prod(shape)) if shape else 1, dtype=dtype)
+    size = int(np.prod(shape)) if shape else 1
+    out, reused = backend.host_blocks.take(size * dtype.itemsize, dtype)
+    count("dataplane.d2h_payload")
+    if reused:
+        count("dataplane.d2h_payload_reused")
     payload = HostPayload(flat=out, shape=shape, dtype=dtype)
     item = payload.itemsize
     eng.config.chunk_bytes = max(item, (eng.config.chunk_bytes // item) * item)

@@ -11,7 +11,8 @@ and TTFT critical-path attribution.
     provably sums to measured TTFT, from the span trees;
   * ``repro.obs.spans``       — ``span(name)``: host spans of the
     transfer engine and the data plane on the JAX profiler's clock,
-    counted in ``SPAN_METRICS`` only while a profiler session runs.
+    counted in ``SPAN_METRICS`` only while a profiler session runs, and
+    ``count(name)``, a count under the same switch.
 
 This package imports nothing from ``repro.core`` (the core imports
 *us*), so instrumentation can thread through every layer without
@@ -32,7 +33,7 @@ from .metrics import (
     LogHistogram,
     MetricsRegistry,
 )
-from .spans import SPAN_METRICS, span
+from .spans import SPAN_METRICS, count, span
 from .tracer import (
     NULL_TRACER,
     NullTracer,
@@ -50,7 +51,7 @@ __all__ = [
     "to_chrome", "validate_chrome_trace", "write_chrome_trace",
     "BinnedTimeline", "Counter", "Gauge", "LogHistogram",
     "MetricsRegistry",
-    "SPAN_METRICS", "span",
+    "SPAN_METRICS", "count", "span",
     "NULL_TRACER", "NullTracer", "Span", "Tracer", "current_tracer",
     "install", "spans_from_dicts", "uninstall",
 ]

@@ -11,6 +11,10 @@ the data plane (``engine.memcpy``, ``dataplane.d2h_chunk``, ...):
     ``<name>.calls`` and the elapsed ``time.perf_counter()`` seconds to
     ``<name>.seconds`` in :data:`SPAN_METRICS`.
 
+``count(name)`` adds 1 to ``<name>.calls`` under the same switch, for an
+event that is counted but not timed (a D2H payload served from the host
+block cache).
+
 The profiler session is the only switch: a process that profiles one
 window finds in ``SPAN_METRICS`` that window's calls and nothing else.
 Pass identifiers as ``args`` (a request's ``req=``) so that the spans of
@@ -81,6 +85,13 @@ def _recorded(name: str, args: dict):
             dt = time.perf_counter() - t0
             SPAN_METRICS.counter(name + ".calls").inc()
             SPAN_METRICS.counter(name + ".seconds").inc(dt)
+
+
+def count(name: str) -> None:
+    """Add 1 to ``<name>.calls`` in :data:`SPAN_METRICS`, only while a
+    profiler session runs (a counted event with no span of its own)."""
+    if _recording():
+        SPAN_METRICS.counter(name + ".calls").inc()
 
 
 def span(name: str, **args: Any) -> ContextManager[None]:

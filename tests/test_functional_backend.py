@@ -217,3 +217,227 @@ def test_thread_stream_end_to_end_async_copy():
     stream.synchronize()
     assert np.array_equal(results["y"], x)
     stream.close()
+
+
+# ---------------------------------------------------------------------------
+# Host block cache: D2H payloads reuse the pages of an earlier sleep
+# ---------------------------------------------------------------------------
+def _params(scale, shapes):
+    """Device weights of ``shapes`` (name -> shape), bf16 and f32, with
+    values that differ by ``scale``."""
+    out = {}
+    for i, (name, shape) in enumerate(sorted(shapes.items())):
+        dtype = jax.numpy.bfloat16 if i % 2 else jax.numpy.float32
+        n = int(np.prod(shape))
+        out[name] = (jax.numpy.arange(n, dtype=jax.numpy.float32) * scale
+                     + i).astype(dtype).reshape(shape)
+    return out
+
+
+# Every leaf spans several 1 KiB chunks, so a woken leaf is a concatenation
+# and never aliases its host block.
+SHAPES_A = {"w": (40, 64), "u": (3, 700), "v": (9, 130)}
+SHAPES_B = {"w": (50, 64), "x": (7, 300)}
+
+
+def _engine():
+    return make_functional_engine(
+        config=MMAConfig(chunk_bytes=1024, fallback_bytes=0))
+
+
+def _addrs(host):
+    return sorted((a.nbytes, a.__array_interface__["data"][0])
+                  for a in jax.tree.leaves(host))
+
+
+def test_sleep_wake_sleep_reuses_the_host_blocks():
+    from repro.serving import WeightManager
+
+    eng = _engine()
+    params = _params(1.0, SHAPES_A)
+    want = {k: np.asarray(v) for k, v in params.items()}
+    wm = WeightManager(eng, params=params)
+    del params
+    wm.sleep()
+    first = _addrs(wm._host_copy)
+    wm.wake()
+    cache = eng.backend.host_blocks
+    assert cache.lent_bytes == 0 and cache.free_bytes == wm.nbytes
+    wm.sleep()
+    assert _addrs(wm._host_copy) == first
+    for k, v in want.items():
+        assert wm._host_copy[k].dtype == v.dtype
+        assert np.array_equal(wm._host_copy[k], v)
+    wm.wake()
+    for k, v in want.items():
+        assert np.array_equal(np.asarray(wm.params[k]), v)
+
+
+def test_slept_leaf_kept_past_the_wake_keeps_its_bytes():
+    """A reshaped slice of a slept leaf and a ``device_put`` of it outlive
+    the wake: their block is not reissued to the two sleeps that follow."""
+    from repro.serving import WeightManager
+
+    eng = _engine()
+    wm = WeightManager(eng, params=_params(1.0, SHAPES_A))
+    wm.sleep()
+    leaf = wm._host_copy["u"]
+    want = leaf.copy()
+    kept = leaf.reshape(-1, 100)[2:5]
+    on_dev = jax.device_put(leaf)
+    del leaf
+    wm.wake()
+    for scale in (-3.0, 7.0):
+        other = WeightManager(eng, params=_params(scale, SHAPES_A))
+        other.sleep()
+        other.wake()
+    assert np.array_equal(kept, want.reshape(-1, 100)[2:5])
+    assert np.array_equal(np.asarray(on_dev), want)
+
+
+def test_two_models_alternating_reuse_their_own_blocks(monkeypatch):
+    """A sleeps and B wakes, then B sleeps and A wakes, on one engine; the
+    leaves of A and B differ in size. From the second cycle on every
+    payload of every sleep is served from the cache."""
+    from repro.core.jax_backend import HostBlockCache
+    from repro.serving import WeightManager
+
+    served = []
+    take = HostBlockCache.take
+
+    def recording_take(self, nbytes, dtype):
+        out, reused = take(self, nbytes, dtype)
+        served.append(reused)
+        return out, reused
+
+    monkeypatch.setattr(HostBlockCache, "take", recording_take)
+    eng = _engine()
+    a = WeightManager(eng, params=_params(1.0, SHAPES_A))
+    b = WeightManager(eng, params=_params(2.0, SHAPES_B))
+    want = {id(m): {k: np.asarray(v) for k, v in m.params.items()}
+            for m in (a, b)}
+    b.sleep()
+    cycles = []
+    for _ in range(3):
+        del served[:]
+        a.switch_to(b)
+        b.switch_to(a)
+        cycles.append(list(served))
+    assert len(cycles[0]) == len(SHAPES_A) + len(SHAPES_B)
+    assert all(all(c) for c in cycles[1:]), cycles
+    for m in (a, b):
+        if m.state == "asleep":
+            m.wake()
+        for k, v in want[id(m)].items():
+            assert np.array_equal(np.asarray(m.params[k]), v)
+
+
+def test_cache_keeps_no_more_free_bytes_than_it_ever_lent():
+    from repro.core.jax_backend import HostBlockCache
+
+    cache = HostBlockCache()
+    rng = np.random.default_rng(3)
+    held = []
+    for _ in range(400):
+        if held and rng.random() < 0.5:
+            held.pop(int(rng.integers(len(held))))
+        else:
+            held.append(cache.take(int(rng.choice([64, 256, 4096, 12288])),
+                                   np.dtype(np.float32))[0])
+        assert cache.lent_bytes == sum(a.nbytes for a in held)
+        assert cache.free_bytes <= cache.peak_lent_bytes
+    del held[:]
+    assert cache.lent_bytes == 0
+    assert 0 < cache.free_bytes <= cache.peak_lent_bytes
+
+
+def test_cache_under_threads_loses_no_block():
+    """Workers on more threads than cores take blocks and drop ones that
+    other threads took, so blocks come back on any thread: no update of
+    the cache's counts or bins is lost."""
+    import os
+    import sys
+
+    from repro.core.jax_backend import HostBlockCache
+
+    cache = HostBlockCache()
+    shared = []
+
+    def work(seed):
+        rng = np.random.default_rng(seed)
+        for _ in range(300):
+            if shared and rng.random() < 0.5:
+                try:
+                    shared.pop()
+                except IndexError:
+                    pass
+            else:
+                shared.append(cache.take(
+                    int(rng.choice([64, 256, 4096])),
+                    np.dtype(np.float32))[0])
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(2 * (os.cpu_count() or 4))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert cache.lent_bytes == sum(a.nbytes for a in shared)
+    del shared[:]
+    assert cache.lent_bytes == 0
+    assert cache.free_bytes == sum(b.nbytes for bin_ in cache._free.values()
+                                   for b in bin_)
+    assert 0 < cache.free_bytes <= cache.peak_lent_bytes
+
+
+def test_engine_none_keeps_nothing(monkeypatch):
+    """``multipath_device_get`` without an engine builds one for the call:
+    its cache goes with it, and the payload it lent stays valid."""
+    import gc
+    import weakref
+
+    from repro.core import jax_backend
+
+    caches = []
+    make = jax_backend.make_functional_engine
+
+    def recording_make(*args, **kwargs):
+        eng = make(*args, **kwargs)
+        caches.append(weakref.ref(eng.backend.host_blocks))
+        return eng
+
+    monkeypatch.setattr(jax_backend, "make_functional_engine", recording_make)
+    x = jax.numpy.arange(3000, dtype=jax.numpy.float32).reshape(30, 100)
+    back = multipath_device_get(x)
+    gc.collect()
+    assert len(caches) == 1 and caches[0]() is None
+    assert np.array_equal(back, np.asarray(x))
+
+
+def test_payload_counters_count_only_under_a_profiler_session(tmp_path):
+    from repro.obs import SPAN_METRICS
+
+    names = ("dataplane.d2h_payload.calls",
+             "dataplane.d2h_payload_reused.calls")
+
+    def counts():
+        return [SPAN_METRICS.counter(n).total() if n in SPAN_METRICS else 0
+                for n in names]
+
+    eng = _engine()
+    x = jax.numpy.ones((20, 64), jax.numpy.float32)
+    before = counts()
+    multipath_device_get(x, engine=eng)
+    assert counts() == before
+    with jax.profiler.trace(str(tmp_path)):
+        multipath_device_get(x, engine=eng)     # the first one's block
+        kept = multipath_device_get(x, engine=eng)
+        multipath_device_get(x, engine=eng)     # a miss: ``kept`` holds it
+    assert [c - b for c, b in zip(counts(), before)] == [3, 2]
+    del kept
