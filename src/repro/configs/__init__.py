@@ -1,10 +1,11 @@
-"""Architecture registry: the 10 assigned architectures (+ the paper's own
-Qwen serving models for end-to-end benchmarks)."""
+"""Architecture registry: the assigned architectures and DeepSeek-V2-Lite
+(+ the paper's own Qwen serving models for end-to-end benchmarks)."""
 from __future__ import annotations
 
 import dataclasses
 
 from .base import INPUT_SHAPES, LONG_CONTEXT_WINDOW, InputShape, ModelConfig
+from .deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 from .gemma_7b import CONFIG as GEMMA_7B
 from .jamba_1_5_large import CONFIG as JAMBA_1_5_LARGE
 from .llama4_maverick_400b import CONFIG as LLAMA4_MAVERICK
@@ -29,6 +30,7 @@ ARCHS = {
         MAMBA2_370M,
         LLAMA4_MAVERICK,
         JAMBA_1_5_LARGE,
+        DEEPSEEK_V2_LITE,
     ]
 }
 

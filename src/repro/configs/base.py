@@ -29,6 +29,10 @@ class ModelConfig:
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
+    n_shared_experts: int = 0    # shared experts: one gated MLP of width
+                                 # n_shared_experts * d_ff, every token
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
     moe_every: int = 1           # MoE channel mixer at layers where
                                  # (i % moe_every == moe_offset)
     moe_offset: int = 0
@@ -47,6 +51,23 @@ class ModelConfig:
     #     layers every N consume precomputed patch/frame embeddings.
     cross_attn_every: int = 0
     n_frontend_tokens: int = 0   # patches / conditioning frames
+    # --- leading dense layers ahead of the scanned periods (DeepSeek's
+    #     first_k_dense_replace), with their own MLP width (0 -> d_ff)
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    # --- multi-head latent attention (MLA); kv_lora_rank 0 = none. The
+    #     cache holds kv_lora_rank + qk_rope_head_dim values per token.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- YaRN rope of the MLA rope dims; factor 1 is plain rope
+    rope_factor: float = 1.0
+    rope_original_max_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # --- attention details ---
     rope_theta: float = 10_000.0
     attn_window: int = 0         # 0 = full causal; >0 = sliding window
@@ -95,6 +116,14 @@ class ModelConfig:
     def uses_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def uses_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def attn_mixer(self) -> str:
+        return "mla" if self.uses_mla else "attn"
+
     # Scan periodicity: the layer stack is a scan over identical
     # super-blocks of ``period`` layers (MaxText-style stacked params).
     @property
@@ -110,12 +139,17 @@ class ModelConfig:
             p = self.cross_attn_every
         elif self.uses_moe and self.moe_every > 1:
             p = self.moe_every
-        assert self.n_layers % p == 0, (self.name, self.n_layers, p)
+        assert (self.n_layers - self.first_k_dense) % p == 0, (
+            self.name, self.n_layers, p)
         return p
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // self.period
+        return (self.n_layers - self.first_k_dense) // self.period
+
+    def lead_plan(self) -> Tuple[Tuple[str, str], ...]:
+        """The leading dense layers, run once ahead of the scanned periods."""
+        return ((self.attn_mixer, "mlp"),) * self.first_k_dense
 
     # Layer descriptors within one period: (mixer, channel) pairs.
     def layer_plan(self) -> Tuple[Tuple[str, str], ...]:
@@ -130,7 +164,7 @@ class ModelConfig:
             ):
                 mixer = "cross_attn"
             else:
-                mixer = "attn"
+                mixer = self.attn_mixer
             if self.family == "ssm":
                 channel = "none" if self.d_ff == 0 else "mlp"
             elif self.uses_moe and p % self.moe_every == self.moe_offset:
@@ -152,12 +186,17 @@ class ModelConfig:
         n_kv = max(1, min(n_kv, n_heads))
         return dataclasses.replace(
             self,
-            n_layers=period * min(2, self.n_periods),
+            n_layers=self.first_k_dense + period * min(2, self.n_periods),
             d_model=d_model,
             n_heads=n_heads,
             n_kv_heads=n_kv,
             head_dim=min(self.hd, 64),
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
+            dense_d_ff=min(self.dense_d_ff, 512),
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             vocab=min(self.vocab, 512),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
@@ -172,12 +211,19 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Approximate parameter count (for payload-size computations)."""
-        d, L = self.d_model, self.n_layers
+        d = self.d_model
         total = self.vocab * d  # embed
         if not self.tie_embeddings:
             total += self.vocab * d
-        for mixer, channel in self.layer_plan() * self.n_periods:
-            if mixer in ("attn", "cross_attn"):
+        H = self.n_heads
+        plan = self.lead_plan() + self.layer_plan() * self.n_periods
+        for i, (mixer, channel) in enumerate(plan):
+            if mixer == "mla":
+                dn, dr = self.qk_nope_head_dim, self.qk_rope_head_dim
+                r, dv = self.kv_lora_rank, self.v_head_dim
+                total += d * H * (dn + dr) + d * (r + dr) + r
+                total += r * H * (dn + dv) + H * dv * d
+            elif mixer in ("attn", "cross_attn"):
                 total += d * self.hd * (self.n_heads + 2 * self.n_kv_heads)
                 total += self.n_heads * self.hd * d
                 if mixer == "cross_attn":
@@ -189,10 +235,13 @@ class ModelConfig:
                 )
                 total += d * (2 * di + 2 * g * s + h) + di * d
             if channel == "mlp":
-                total += 3 * d * self.d_ff
+                lead = i < self.first_k_dense
+                total += 3 * d * (self.dense_d_ff if lead and self.dense_d_ff
+                                  else self.d_ff)
             elif channel == "moe":
                 total += d * self.n_experts  # router
-                total += self.n_experts * 3 * d * self.d_ff
+                total += (self.n_experts + self.n_shared_experts) \
+                    * 3 * d * self.d_ff
         total += d  # final norm
         return total
 

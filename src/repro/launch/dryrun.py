@@ -102,15 +102,16 @@ def _body_cost(cfg, shape, mesh, kind, specs, params) -> Optional[Dict]:
         lowered = jitted.lower(pp, x, *f_args)
     else:
         if "caches" in specs:
-            caches_p = strip(specs["caches"])
+            caches_p = specs["caches"]
         else:  # prefill creates its caches internally; rebuild abstractly
             from ..models.transformer import init_caches
 
-            caches_p = strip(
-                jax.eval_shape(
-                    lambda: init_caches(cfg, B, shape.seq_len, W)
-                )
+            caches_p = jax.eval_shape(
+                lambda: init_caches(cfg, B, shape.seq_len, W)
             )
+        if cfg.first_k_dense:      # one scanned period: not the lead layers
+            caches_p = caches_p["blocks"]
+        caches_p = strip(caches_p)
         c_sh = cache_shardings(caches_p, mesh)
         if kind == "prefill":
             def body(pp, x, caches, *fa):

@@ -17,12 +17,23 @@ def _dense(rng, shape, dtype, scale=None):
     return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(dtype)
 
 
-def init_layer(rng, cfg, mixer: str, channel: str) -> Dict:
+def init_layer(rng, cfg, mixer: str, channel: str, d_ff: int = 0) -> Dict:
+    """One layer's leaves; ``d_ff`` overrides the MLP width (a leading
+    dense layer's)."""
     d, dt = cfg.d_model, cfg.dtype
     H, G, D = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    d_ff = d_ff or cfg.d_ff
     ks = jax.random.split(rng, 24)
     p: Dict = {"ln1": jnp.ones((d,), dt)}
-    if mixer in ("attn", "cross_attn"):
+    if mixer == "mla":
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        r, dv = cfg.kv_lora_rank, cfg.v_head_dim
+        p["wq"] = _dense(ks[0], (d, H * (dn + dr)), dt)
+        p["w_kv_a"] = _dense(ks[1], (d, r + dr), dt)
+        p["kv_norm"] = jnp.ones((r,), dt)
+        p["w_kv_b"] = _dense(ks[2], (r, H * (dn + dv)), dt)
+        p["wo"] = _dense(ks[3], (H * dv, d), dt)
+    elif mixer in ("attn", "cross_attn"):
         p["wq"] = _dense(ks[0], (d, H * D), dt)
         p["wo"] = _dense(ks[3], (H * D, d), dt)
         if mixer == "attn":
@@ -58,9 +69,9 @@ def init_layer(rng, cfg, mixer: str, channel: str) -> Dict:
         p["w_out"] = _dense(ks[12], (di, d), dt)
     if channel == "mlp":
         p["ln2"] = jnp.ones((d,), dt)
-        p["w_gate"] = _dense(ks[13], (d, cfg.d_ff), dt)
-        p["w_up"] = _dense(ks[14], (d, cfg.d_ff), dt)
-        p["w_down"] = _dense(ks[15], (cfg.d_ff, d), dt)
+        p["w_gate"] = _dense(ks[13], (d, d_ff), dt)
+        p["w_up"] = _dense(ks[14], (d, d_ff), dt)
+        p["w_down"] = _dense(ks[15], (d_ff, d), dt)
     elif channel == "moe":
         E = cfg.n_experts
         p["ln2"] = jnp.ones((d,), dt)
@@ -68,6 +79,11 @@ def init_layer(rng, cfg, mixer: str, channel: str) -> Dict:
         p["w_gate"] = _dense(ks[17], (E, d, cfg.d_ff), dt)
         p["w_up"] = _dense(ks[18], (E, d, cfg.d_ff), dt)
         p["w_down"] = _dense(ks[19], (E, cfg.d_ff, d), dt)
+        if cfg.n_shared_experts:
+            f = cfg.n_shared_experts * cfg.d_ff
+            p["ws_gate"] = _dense(ks[20], (d, f), dt)
+            p["ws_up"] = _dense(ks[21], (d, f), dt)
+            p["ws_down"] = _dense(ks[22], (f, d), dt)
     return p
 
 
@@ -89,6 +105,13 @@ def init_params(rng, cfg) -> Dict:
     }
     if not cfg.tie_embeddings:
         params["head"] = _dense(k_head, (cfg.d_model, cfg.vocab), cfg.dtype)
+    if cfg.first_k_dense:
+        params["lead"] = [
+            init_layer(k, cfg, mixer, channel, d_ff=cfg.dense_d_ff)
+            for k, (mixer, channel) in zip(
+                jax.random.split(jax.random.fold_in(rng, 3), cfg.first_k_dense),
+                cfg.lead_plan())
+        ]
     # vmap over periods stacks every leaf with a leading n_periods axis
     params["blocks"] = jax.vmap(lambda k: init_period(k, cfg))(
         jax.random.split(k_blocks, cfg.n_periods)

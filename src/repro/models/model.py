@@ -23,12 +23,14 @@ def forward(
     cache_len: Optional[jax.Array] = None,
     window: int = 0,
     remat: bool = False,
+    last_only: bool = False,
 ):
-    """Returns (logits, new_caches, aux_loss).
+    """Returns (logits, new_caches, aux) (``run_blocks``'s aux).
 
     ``inputs_embeds`` replaces token embedding for audio frontends
     (precomputed frame embeddings — the stubbed modality carve-out);
     ``frontend`` feeds cross-attention layers (VLM patch embeddings).
+    ``last_only`` runs the final norm and the head at the last position.
     """
     if inputs_embeds is not None:
         x = inputs_embeds.astype(cfg.dtype)
@@ -39,6 +41,8 @@ def forward(
         params, x, cfg, mode=mode, frontend=frontend, caches=caches,
         cache_len=cache_len, window=window, remat=remat,
     )
+    if last_only:
+        x = x[:, -1:]
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = unembed(params, x)
     logits = shard(logits, BATCH, None, MODEL)
@@ -73,18 +77,23 @@ def prefill(
     window: int = 0,
     frontend: Optional[jax.Array] = None,
     inputs_embeds: Optional[jax.Array] = None,
+    routing: bool = False,
 ):
-    """Process a prompt, returning (last-position logits, caches, length)."""
+    """Process a prompt, returning (last-position logits, caches, length),
+    and with ``routing`` (a MoE model) also the number of assignments to
+    each expert, (MoE layers, n_experts) int32. The output head runs at
+    the last position only."""
     B, S = (
         tokens.shape if tokens is not None else inputs_embeds.shape[:2]
     )
     caches = init_caches(cfg, B, max_len, window)
-    logits, caches, _ = forward(
+    logits, caches, aux = forward(
         params, tokens, cfg, mode="prefill", caches=caches,
         cache_len=jnp.zeros((), jnp.int32), window=window,
-        frontend=frontend, inputs_embeds=inputs_embeds,
+        frontend=frontend, inputs_embeds=inputs_embeds, last_only=True,
     )
-    return logits[:, -1], caches, jnp.array(S, jnp.int32)
+    out = (logits[:, 0], caches, jnp.array(S, jnp.int32))
+    return out + (aux,) if routing else out
 
 
 def decode_step(

@@ -29,11 +29,13 @@ def _batch_axes(sizes) -> Tuple[str, ...]:
 
 def moe_ffn_ep(params: Dict, x: jax.Array, cfg, *, return_aux: bool = False):
     """Drop-in for moe_ffn; falls back when no model axis / E not
-    divisible. x: (B, S, d)."""
+    divisible, or for a router this exchange does not implement (shared
+    experts, top-k weights not renormalised). x: (B, S, d)."""
     sizes = mesh_axis_sizes()
     m = sizes.get("model", 1)
     E, K = cfg.n_experts, cfg.top_k
-    if m == 1 or E % m != 0:
+    if m == 1 or E % m != 0 or cfg.n_shared_experts \
+            or not cfg.norm_topk_prob or cfg.routed_scaling_factor != 1:
         return moe_ffn(params, x, cfg, return_aux=return_aux)
 
     mesh = _current_mesh()

@@ -11,9 +11,11 @@ the data plane (``engine.memcpy``, ``dataplane.d2h_chunk``, ...):
     ``<name>.calls`` and the elapsed ``time.perf_counter()`` seconds to
     ``<name>.seconds`` in :data:`SPAN_METRICS`.
 
-``count(name)`` adds 1 to ``<name>.calls`` under the same switch, for an
-event that is counted but not timed (a D2H payload served from the host
-block cache).
+``count(name, n=1)`` adds ``n`` to ``<name>.calls`` under the same switch,
+for an event or an amount that is counted but not timed (a D2H payload
+served from the host block cache, the tokens of a KV offload).
+``recording()`` says whether a session runs, for a count whose amount
+costs something to read.
 
 The profiler session is the only switch: a process that profiles one
 window finds in ``SPAN_METRICS`` that window's calls and nothing else.
@@ -87,11 +89,17 @@ def _recorded(name: str, args: dict):
             SPAN_METRICS.counter(name + ".seconds").inc(dt)
 
 
-def count(name: str) -> None:
-    """Add 1 to ``<name>.calls`` in :data:`SPAN_METRICS`, only while a
-    profiler session runs (a counted event with no span of its own)."""
+def recording() -> bool:
+    """Whether a profiler session runs, so that spans and counts record."""
+    return _recording()
+
+
+def count(name: str, n: float = 1) -> None:
+    """Add ``n`` to ``<name>.calls`` in :data:`SPAN_METRICS`, only while a
+    profiler session runs (a counted event or amount with no span of its
+    own)."""
     if _recording():
-        SPAN_METRICS.counter(name + ".calls").inc()
+        SPAN_METRICS.counter(name + ".calls").inc(n)
 
 
 def span(name: str, **args: Any) -> ContextManager[None]:

@@ -32,6 +32,7 @@ from ..core import (
 )
 from ..core.config import GB, MMAConfig
 from ..models import decode_step, init_params, prefill
+from ..obs import count, recording
 from .kv_cache import KVCacheManager, kv_bytes_per_token
 from .scheduler import Request, Scheduler
 
@@ -44,7 +45,8 @@ DECODE_EFF = 0.6              # achieved fraction of HBM bw during decode
 # The served model, compiled once per (cfg, max_len, window) and input
 # shape: called eagerly, the layer scan is compiled again on every call.
 # A decode step donates the caches it replaces.
-jit_prefill = jax.jit(prefill, static_argnames=("cfg", "max_len", "window"))
+jit_prefill = jax.jit(prefill,
+                      static_argnames=("cfg", "max_len", "window", "routing"))
 jit_decode_step = jax.jit(
     decode_step, static_argnames=("cfg", "window"), donate_argnames=("caches",)
 )
@@ -290,9 +292,20 @@ class FunctionalServer:
             # KV, verified by tests); a payload round-trip would skip it.
             self.transfer_log.append(("fetch", hit))
             req.hit_tokens = hit
-        logits, caches, clen = jit_prefill(
-            self.params, toks, self.cfg, max_len=self.max_len
-        )
+        if self.cfg.uses_moe:
+            logits, caches, clen, routing = jit_prefill(
+                self.params, toks, self.cfg, max_len=self.max_len,
+                routing=True,
+            )
+            if recording():
+                # (MoE layers, experts) assignments, fetched only when traced
+                per_expert = np.asarray(routing)
+                count("moe.assignments", int(per_expert.sum()))
+                count("moe.assignments_max", int(per_expert.max(-1).sum()))
+        else:
+            logits, caches, clen = jit_prefill(
+                self.params, toks, self.cfg, max_len=self.max_len
+            )
         req.context = {"caches": caches, "cache_len": clen}
         req.generated.append(int(jnp.argmax(logits[0])))
         req.ttft = time.monotonic() - t0

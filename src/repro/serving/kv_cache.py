@@ -28,14 +28,16 @@ import numpy as np
 from ..core import Direction, MMAEngine, TrafficClass, TransferSpec
 from ..core.config import GB, MMAConfig
 from ..kvstore import TieredKVStore, chain_keys, legacy_prefix_key
+from ..obs import count
 
 
 def kv_bytes_per_token(cfg, dtype_size: int = 2) -> int:
-    """Bytes of K+V per token across all attention layers."""
-    n_attn = sum(
-        1 for mixer, _ in cfg.layer_plan() if mixer == "attn"
-    ) * cfg.n_periods
-    return 2 * cfg.n_kv_heads * cfg.hd * n_attn * dtype_size
+    """Bytes of cache per token across all attention layers: K and V of
+    every KV head, or an MLA layer's latent and shared roped key."""
+    plan = cfg.lead_plan() + cfg.layer_plan() * cfg.n_periods
+    per_layer = {"attn": 2 * cfg.n_kv_heads * cfg.hd,
+                 "mla": cfg.kv_lora_rank + cfg.qk_rope_head_dim}
+    return sum(per_layer.get(mixer, 0) for mixer, _ in plan) * dtype_size
 
 
 def ssm_state_bytes(cfg, batch: int = 1, dtype_size: int = 2) -> int:
@@ -263,6 +265,8 @@ class KVCacheManager:
                 exact_only=self.cfg.uses_ssm,
             )
         self.release_if_admitted(len(tokens))
+        count("kv.offload_tokens", len(tokens))
+        count("kv.offload_bytes", len(tokens) * self.bytes_per_token)
         return key, task
 
     def fetch(

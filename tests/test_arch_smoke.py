@@ -49,7 +49,8 @@ def _batch(cfg, B=2, S=32, seed=0):
 @pytest.mark.parametrize("name", ALL_ARCHS)
 def test_reduced_config_invariants(name):
     cfg = get_config(name).reduced()
-    assert cfg.n_layers <= 2 * cfg.period
+    # leading dense layers run once, ahead of the scanned super-blocks
+    assert cfg.n_layers - cfg.first_k_dense <= 2 * cfg.period
     assert cfg.d_model <= 512
     assert cfg.n_experts <= 4
     assert cfg.family == ARCHS[name].family
