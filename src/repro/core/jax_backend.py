@@ -14,7 +14,11 @@ bit-exactness and exercises the Sync Engine with real threads.
 Each copy is issued when its chunk is pulled, but the completion is held
 until the dispatch round that pulled it has asked every link
 (``settle``): delivered inline, it would let the first link pull every
-chunk before a relay link had its turn.
+chunk before a relay link had its turn. A D2H chunk's host copy starts
+at its pull (``copy_to_host_async``) and is landed, waited on and stored
+into the payload, when ``settle`` reaches its completion; so with a
+queue depth of 2 the next chunk's copy is in flight while this one is
+waited on and stored.
 
 A D2H payload comes from the backend's ``HostBlockCache``: the next sleep
 of the same sizes stores into pages the last one already faulted in.
@@ -22,6 +26,7 @@ of the same sizes stores into pages the last one already faulted in.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 import weakref
@@ -165,6 +170,7 @@ class JaxBackend(Backend):
         self.host_blocks = HostBlockCache()
         self._done: Deque[Callable[[], None]] = deque()
         self._settling = False
+        self._d2h_issued = 0    # D2H copies started so far
 
     def now(self) -> float:
         return time.monotonic()
@@ -198,16 +204,32 @@ class JaxBackend(Backend):
             assembler: ChunkAssembler = task.dst
             assembler.add(mt.seq, chunk)
         else:
-            with span("dataplane.d2h_chunk"):
+            with span("dataplane.d2h_issue"):
                 src_flat: jax.Array = task.src                  # on target device
                 piece = src_flat[lo:hi]
                 if not route.is_direct:
                     piece = jax.device_put(piece, relay_dev)    # target -> relay (ICI)
-                with span("dataplane.d2h_wait"):
-                    host = np.asarray(piece)                    # relay/target -> host
-                with span("dataplane.d2h_store"):
-                    payload.flat[lo:hi] = host
+                piece.copy_to_host_async()                      # relay/target -> host
+            self._d2h_issued += 1
+            on_done = functools.partial(
+                self._land, piece, payload.flat[lo:hi], self._d2h_issued,
+                on_done)
         self._done.append(on_done)
+
+    def _land(self, piece: jax.Array, dst: np.ndarray, issued: int,
+              on_done: Callable[[], None]) -> None:
+        """Land one D2H chunk, the ``issued``-th copy started: wait for its
+        copy, store it into ``dst`` (its span of the payload) and deliver
+        its completion, which on a link with room issues the next chunk."""
+        with span("dataplane.d2h_chunk"):
+            # 1 when a later copy is in flight; a 0 still makes the counter,
+            # which tells a landing with none from a program without it.
+            count("dataplane.d2h_overlapped", int(self._d2h_issued > issued))
+            with span("dataplane.d2h_wait"):
+                host = np.asarray(piece)
+            with span("dataplane.d2h_store"):
+                dst[...] = host
+            on_done()
 
     def settle(self) -> None:
         """Deliver held completions until none is left; each may pull and

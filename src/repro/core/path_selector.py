@@ -15,8 +15,9 @@ capacity:
     stop pulling; fast paths drain and pull more. No explicit link-state
     feedback is used.
   * **Contention backoff** — a worker whose observed chunk service time
-    exceeds ``backoff_factor`` x nominal pulls only when its queue is empty,
-    yielding to latency-sensitive background traffic.
+    exceeds ``backoff_factor`` x its best in the chunk's direction pulls
+    only when its queue is empty, yielding to latency-sensitive
+    background traffic.
   * **QoS class arbitration** — every pop is class-ordered (strict LATENCY
     first, weighted-fair below); relay stealing serves higher classes across
     all links before lower ones, and while a LATENCY flow is in flight its
@@ -52,11 +53,11 @@ from __future__ import annotations
 import dataclasses
 import math
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, TYPE_CHECKING
+from typing import Callable, Deque, Dict, List, Optional, Tuple, TYPE_CHECKING
 
 from .config import MMAConfig
 from .topology import Topology
-from .transfer_task import MicroTask, MicroTaskQueue, TrafficClass
+from .transfer_task import Direction, MicroTask, MicroTaskQueue, TrafficClass
 
 if TYPE_CHECKING:  # pragma: no cover
     from .task_launcher import Backend
@@ -115,6 +116,12 @@ class LinkWorker:
         # self-calibrated uncontended reference (PCIe exposes no explicit
         # congestion signal, so the only baseline is our own history).
         self.best_service: Optional[float] = None
+        # The contention verdict's own (EWMA, best) per-byte service, kept
+        # per direction: PCIe is full duplex, and one direction's service
+        # says nothing of the other's (a functional H2D chunk lands on its
+        # dispatch, a D2H chunk on its copy, several times slower a byte).
+        self._service_by_dir: Dict[Direction, Tuple[float, float]] = {}
+        # Verdict of the direction whose chunk landed last.
         self.contended = False
         self.enabled = True
         # -- online estimator state (always maintained; the adapt_* knobs
@@ -284,10 +291,12 @@ class LinkWorker:
             )
             if self.best_service is None or per_byte < self.best_service:
                 self.best_service = per_byte
-            self.contended = (
-                self.ewma_service
-                > self.config.backoff_factor * self.best_service
-            )
+            ewma, best = self._service_by_dir.get(
+                mt.direction, (per_byte, per_byte))
+            ewma = a * per_byte + (1 - a) * ewma
+            best = min(best, per_byte)
+            self._service_by_dir[mt.direction] = (ewma, best)
+            self.contended = ewma > self.config.backoff_factor * best
             self.samples += 1
             self.ewma_updated_at = self.backend.now()
             self.latency_ewma = (

@@ -48,8 +48,8 @@ class Backend:
     ) -> Optional[PreemptHandle]:
         """Start moving one chunk. May return a ``PreemptHandle`` when the
         backend supports cooperative in-flight recall (the simulator
-        does; the functional backend copies synchronously and returns
-        None)."""
+        does; the functional backend's copies cannot be recalled, and it
+        returns None)."""
         raise NotImplementedError
 
     def settle(self) -> None:

@@ -233,6 +233,62 @@ def test_backpressure_shifts_work_off_congested_link():
     assert w1.bytes_total < 0.75 * w2.bytes_total
 
 
+def _launch_depths(backend, worker):
+    """Record ``worker.outstanding`` (this chunk included) at every launch."""
+    depths = []
+    launch = backend.launch
+
+    def spy(mt, route, on_done):
+        depths.append(worker.outstanding)
+        return launch(mt, route, on_done)
+
+    backend.launch = spy
+    return depths
+
+
+def _copy(eng, world, direction, nbytes=250 * MB):
+    task = eng.memcpy(nbytes, device=0, direction=direction)
+    while task.state != TaskState.COMPLETE:
+        world.run(until=world.now + 1e-3)
+
+
+def test_d2h_after_fast_h2d_history_pulls_to_full_depth():
+    """A link whose D2H moves a byte 5x slower than its H2D: the D2H
+    chunks are judged against D2H history alone, so after a fast H2D the
+    worker still keeps two D2H chunks outstanding and is not contended."""
+    eng, world, backend = make_sim_engine(
+        config=MMAConfig(fallback_bytes=0), devices=[0])
+    backend.set_link_degradation("pcie_d2h", 0, multiplier=0.2)
+    w = eng.workers[0]
+    _copy(eng, world, Direction.H2D)
+    depths = _launch_depths(backend, w)
+    _copy(eng, world, Direction.D2H)
+    assert not w.contended
+    assert depths[0] == 1 and set(depths[1:]) == {2}, depths
+
+
+def test_link_congested_in_one_direction_backs_off_in_that_direction():
+    """Native traffic on the D2H wire: the worker's D2H chunks slow past
+    ``backoff_factor`` and it pulls at depth 1; its H2D chunks, on the
+    idle other direction, are not held back by it."""
+    eng, world, backend = make_sim_engine(
+        config=MMAConfig(fallback_bytes=0), devices=[0])
+    w = eng.workers[0]
+    for direction in (Direction.H2D, Direction.D2H):
+        _copy(eng, world, direction, 64 * MB)
+        assert not w.contended
+    BackgroundFlow(world, stages=[(backend.pcie_d2h[0], 1.0)],
+                   t_start=world.now, t_stop=world.now + 1.0)
+    depths = _launch_depths(backend, w)
+    _copy(eng, world, Direction.D2H)
+    assert w.contended
+    assert depths.count(1) > 0.9 * len(depths), depths
+    del depths[:]
+    _copy(eng, world, Direction.H2D)
+    assert not w.contended
+    assert depths.count(2) > 0.9 * len(depths), depths
+
+
 def test_outstanding_queue_capacity_respected():
     cfg = MMAConfig(queue_depth=2)
     eng, world, _ = make_sim_engine(config=cfg)

@@ -441,3 +441,160 @@ def test_payload_counters_count_only_under_a_profiler_session(tmp_path):
         multipath_device_get(x, engine=eng)     # a miss: ``kept`` holds it
     assert [c - b for c, b in zip(counts(), before)] == [3, 2]
     del kept
+
+
+# ---------------------------------------------------------------------------
+# D2H: a chunk's host copy starts when it is pulled and lands in ``settle``
+# ---------------------------------------------------------------------------
+def _d2h_events(monkeypatch):
+    """Record ``("issue",)`` for every host copy started and
+    ``("land", k)`` for the landing of the k-th one."""
+    from repro.core.jax_backend import JaxBackend
+
+    events = []
+    array_type = type(jax.numpy.zeros(1))
+    issue = array_type.copy_to_host_async
+    land = JaxBackend._land
+
+    def spy_issue(self):
+        events.append(("issue",))
+        return issue(self)
+
+    def spy_land(self, piece, dst, issued, on_done):
+        events.append(("land", issued))
+        return land(self, piece, dst, issued, on_done)
+
+    monkeypatch.setattr(array_type, "copy_to_host_async", spy_issue)
+    monkeypatch.setattr(JaxBackend, "_land", spy_land)
+    return events
+
+
+def _no_backoff(**kw):
+    """1 KiB chunks with the contention backoff off: these tests pin the
+    data plane's order of issue and landing, and the backoff's verdict on
+    chunks of microseconds on a shared CPU is noise (its own tests are in
+    ``test_core_engine.py``)."""
+    return MMAConfig(chunk_bytes=1024, fallback_bytes=0,
+                     backoff_enabled=False, **kw)
+
+
+def test_d2h_issues_the_next_copy_before_landing_a_chunk(monkeypatch):
+    """One link at queue depth 2: every chunk but the last lands while the
+    copy of the chunk after it is already in flight, and chunks land in
+    the order they were issued."""
+    eng = make_functional_engine(
+        devices=jax.devices()[:1], config=_no_backoff(queue_depth=2))
+    x = jax.numpy.arange(40 * 256, dtype=jax.numpy.float32)   # 40 chunks
+    events = _d2h_events(monkeypatch)
+    back = multipath_device_get(x, engine=eng)
+    assert np.array_equal(back, np.asarray(x))
+    landed = [e[1] for e in events if e[0] == "land"]
+    assert landed == list(range(1, 41))
+    for i, e in enumerate(events):
+        if e[0] == "land" and e[1] < 40:
+            issued_before = sum(1 for f in events[:i] if f[0] == "issue")
+            assert issued_before >= e[1] + 1, events[:i + 1]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float16, np.int32, np.int8])
+@pytest.mark.parametrize("n", [1, 7, 1023, 4096, 10_001])
+def test_d2h_bit_exact_dtypes_and_odd_sizes(dtype, n):
+    """Chunk sizes that don't divide the payload, every dtype: the landed
+    payload is the device array, bit for bit."""
+    rng = np.random.default_rng(n)
+    x = (rng.standard_normal(n) * 100).astype(dtype)
+    eng = make_functional_engine(
+        config=MMAConfig(chunk_bytes=1000, fallback_bytes=0))
+    back = multipath_device_get(jax.device_put(x), engine=eng)
+    assert back.dtype == dtype and np.array_equal(back, x)
+
+
+def test_d2h_returns_only_after_every_chunk_is_stored(monkeypatch):
+    """``multipath_device_get`` returns a payload whose every chunk has
+    landed: no landing is left held, and the bytes are all there."""
+    eng = make_functional_engine(
+        config=MMAConfig(chunk_bytes=4096, fallback_bytes=0))
+    x = jax.numpy.arange(3 * 5000, dtype=jax.numpy.int32).reshape(3, 5000)
+    events = _d2h_events(monkeypatch)
+    back = multipath_device_get(x, engine=eng)
+    n_chunks = eng.config.n_chunks(x.nbytes)
+    assert sum(e[0] == "land" for e in events) == n_chunks
+    assert sum(e[0] == "issue" for e in events) == n_chunks
+    assert not eng.backend._done
+    assert np.array_equal(back, np.asarray(x))
+
+
+def test_d2h_overlap_counter_and_reuse_under_a_profiler_session(tmp_path):
+    """Under a profiler session the second sleep of one engine is served
+    from the host block cache, and every chunk of a leaf but its last is
+    counted as waited on while a later copy was in flight."""
+    from repro.obs import SPAN_METRICS
+    from repro.serving import WeightManager
+
+    names = ("dataplane.d2h_overlapped.calls", "dataplane.d2h_chunk.calls",
+             "dataplane.d2h_issue.calls", "dataplane.d2h_payload.calls",
+             "dataplane.d2h_payload_reused.calls")
+
+    def counts():
+        return {n: SPAN_METRICS.counter(n).total() if n in SPAN_METRICS
+                else 0 for n in names}
+
+    eng = make_functional_engine(devices=jax.devices()[:1],
+                                 config=_no_backoff())
+    wm = WeightManager(eng, params=_params(1.0, SHAPES_A))
+    wm.sleep()
+    wm.wake()
+    before = counts()
+    with jax.profiler.trace(str(tmp_path)):
+        wm.sleep()
+    got = {n: counts()[n] - before[n] for n in names}
+    chunks = sum(eng.config.n_chunks(int(np.prod(s)) * (2 if i % 2 else 4))
+                 for i, s in enumerate(v for _, v in sorted(SHAPES_A.items())))
+    assert got["dataplane.d2h_chunk.calls"] == chunks
+    assert got["dataplane.d2h_issue.calls"] == chunks
+    assert got["dataplane.d2h_overlapped.calls"] == chunks - len(SHAPES_A)
+    assert got["dataplane.d2h_payload.calls"] == len(SHAPES_A)
+    assert got["dataplane.d2h_payload_reused.calls"] == len(SHAPES_A)
+    wm.wake()
+
+
+def test_d2h_relay_routes_bit_exact_subprocess():
+    """Relayed D2H chunks (direct priority off, 4 devices) take the same
+    issue and landing: every dtype and an odd size come back exact, and
+    up to two copies per link are in flight at once."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import numpy as np, jax\n"
+        "from repro.core import MMAConfig, make_functional_engine, multipath_device_get\n"
+        "from repro.core.jax_backend import JaxBackend\n"
+        "assert len(jax.devices()) == 4\n"
+        "inflight = []\n"
+        "land = JaxBackend._land\n"
+        "def spy(self, *a):\n"
+        "    inflight.append(self._d2h_issued - a[2] + 1)\n"
+        "    return land(self, *a)\n"
+        "JaxBackend._land = spy\n"
+        "for dt in (np.float32, np.float16, np.int32, np.int8):\n"
+        "    x = (np.random.default_rng(5).standard_normal(10_001) * 100).astype(dt)\n"
+        "    eng = make_functional_engine(config=MMAConfig(\n"
+        "        chunk_bytes=1000, fallback_bytes=0, direct_priority=False))\n"
+        "    back = multipath_device_get(jax.device_put(x, jax.devices()[2]),\n"
+        "                                target=2, engine=eng)\n"
+        "    assert back.dtype == dt and np.array_equal(back, x), dt\n"
+        "    assert sum(w.chunks_relay for w in eng.workers.values()) > 0\n"
+        "assert 1 < max(inflight) <= 8, max(inflight)\n"
+        "print('D2H_RELAY_OK')\n"
+    )
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr
+    assert "D2H_RELAY_OK" in out.stdout
